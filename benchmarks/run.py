@@ -31,6 +31,8 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 
+from repro.launch.cache import use_compile_cache
+
 ROOT = Path(__file__).resolve().parent.parent
 
 
@@ -267,6 +269,7 @@ def main(argv=None) -> int:
                     help="comma-separated subset (names: %s)"
                          % ",".join(SECTIONS))
     args = ap.parse_args(argv)
+    use_compile_cache()
     table = SMOKE_SECTIONS if args.smoke else SECTIONS
     if args.sections:
         requested = [s.strip() for s in args.sections.split(",") if s.strip()]

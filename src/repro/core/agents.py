@@ -676,7 +676,7 @@ class ShardedAgent(XlaAgent):
     def _device_execute(self, record: KernelRecord, args, kwargs):
         if self.mesh is None:
             raise RuntimeError("ShardedAgent has no mesh attached")
-        with jax.sharding.use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return super()._device_execute(record, args, kwargs)
 
 

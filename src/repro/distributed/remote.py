@@ -703,7 +703,6 @@ def _src_root() -> str:
 
 def spawn_worker(name: str = "w0", devices: Optional[int] = None,
                  platforms: Sequence[str] = ("xla", "jnp"),
-                 jax_platforms: str = "cpu",
                  timeout: Optional[float] = None,
                  env: Optional[Dict[str, str]] = None) -> RemoteWorker:
     """Launch ``python -m repro.launch.worker`` and connect it back.
@@ -714,7 +713,10 @@ def spawn_worker(name: str = "w0", devices: Optional[int] = None,
     the given substrates.  The parent's environment is inherited — so
     ``HALO_TUNING_DB`` / ``HALO_AUTOTUNE_CACHE`` give workers the same
     tuned-config and warm-start tables as the host — with transport
-    details overridden by ``env``.  Blocks until the worker's hello frame
+    details overridden by ``env``.  The child always runs JAX on the CPU
+    (``JAX_PLATFORMS=cpu``, whatever the parent or ``env`` says): a parent
+    that has touched JAX holds its accelerator, and a second process that
+    asks for it fails or hangs.  Blocks until the worker's hello frame
     (default budget ``HALO_WORKER_TIMEOUT``, 120 s: the child pays a full
     jax import)."""
     devices = devices if devices is not None \
@@ -731,11 +733,11 @@ def spawn_worker(name: str = "w0", devices: Optional[int] = None,
     child_env["XLA_FLAGS"] = (
         f"{xla_flags} --xla_force_host_platform_device_count={devices}"
         .strip())
-    child_env.setdefault("JAX_PLATFORMS", jax_platforms)
     child_env["PYTHONPATH"] = os.pathsep.join(
         p for p in [_src_root(), child_env.get("PYTHONPATH", "")] if p)
     if env:
         child_env.update(env)
+    child_env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "repro.launch.worker",
            "--connect", f"127.0.0.1:{port}", "--name", name,
            "--platforms", ",".join(platforms), "--devices", str(devices)]

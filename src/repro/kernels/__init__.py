@@ -15,7 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import GLOBAL_REGISTRY, KernelAttributes, KernelRecord
-from .common import small_enough_off_tpu
+from ..distributed.sharding import current_context
+from .common import peak_bf16_flops, small_enough_off_tpu
 
 _REGISTERED = False
 
@@ -42,9 +43,25 @@ def _ewise_ok(*args, **kw) -> bool:
             and _pallas_ok(*args, **kw))
 
 
+def _unpartitioned() -> bool:
+    """True unless model code is tracing under a multi-device mesh: the SPMD
+    partitioner cannot split a Mosaic kernel, so there the pallas records
+    step aside and the partitionable xla records serve the alias."""
+    mesh = current_context().mesh
+    return mesh is None or mesh.size == 1
+
+
+def _pallas_supports(supports):
+    if supports is None:
+        return lambda *args, **kw: _unpartitioned()
+    return lambda *args, **kw: _unpartitioned() and supports(*args, **kw)
+
+
 def _rec(alias, fn, platform, prio, *, failsafe=False, supports=None,
          cost=None, space=None, doc=""):
     hw = _TPU_ATTRS if platform == "pallas" else _ANY_ATTRS
+    if platform == "pallas":
+        supports = _pallas_supports(supports)
     if platform == "pallas" and jax.default_backend() != "tpu":
         # Table-II cost models are per-hardware attributes calibrated for
         # the TPU target; off-TPU the pallas records run in interpret mode
@@ -93,10 +110,12 @@ def register_all(registry=None) -> None:
     from .sorthist import hist, hist_ref, sort, sort_ref
     from .sorthist.ops import hist_space, sort_space
 
+    peak = peak_bf16_flops()
+
     def mmm_cost(a, b, **kw):
         m, k = a.shape
         n = b.shape[1]
-        return 2.0 * m * n * k / 197e12
+        return 2.0 * m * n * k / peak
 
     # tunable-config axes for the xla records that expose tile kwargs
     # (the chunked mea formulation tiles its q/kv block loop like the
@@ -115,7 +134,8 @@ def register_all(registry=None) -> None:
 
     table = [
         # (alias, ref_fn, xla_fn, pallas_fn, cost, pallas_space)
-        ("MMM", mmm_ref, mmm_xla, mmm, mmm_cost, mmm_space),
+        ("MMM", mmm_ref, mmm_xla, mmm, mmm_cost if peak else None,
+         mmm_space),
         ("EWMM", ewmm_ref, ewmm_ref, ewmm, None, ewise_space),
         ("EWMD", ewmd_ref, ewmd_ref, ewmd, None, ewise_space),
         ("EWADD", ewadd_ref, ewadd_ref, ewadd, None, ewise_space),
@@ -156,7 +176,11 @@ def register_all(registry=None) -> None:
 
     registry.register(_rec("SMMM", smmm_xla, "jnp", 0, failsafe=True))
     registry.register(_rec("SMMM", smmm_xla, "xla", 10))
-    registry.register(_rec("SMMM", smmm, "pallas", 20, supports=_pallas_ok,
+    def _smmm_ok(values, indices, b, **kw):
+        # the blocked-ELL column indices are int32 by construction
+        return _floaty(values, b) and small_enough_off_tpu(values, indices, b)
+
+    registry.register(_rec("SMMM", smmm, "pallas", 20, supports=_smmm_ok,
                            space=smmm_space))
 
     # Sequence-model substrate aliases (no pallas variant: the chunked SSD

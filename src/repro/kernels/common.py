@@ -16,6 +16,17 @@ LANE = 128      # last-dim tile (VREG lane count / MXU edge)
 SUBLANE = 8     # second-to-last-dim tile for f32
 
 
+#: Peak dense bf16 matrix-unit rate of one chip, keyed by ``device_kind``.
+#: TPU v5e ("TPU v5 lite"): 197 TFLOP/s (Google Cloud documentation,
+#: "TPU v5e").  A kind missing here has no analytic cost estimate.
+PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
+
+
+def peak_bf16_flops() -> Optional[float]:
+    """Peak bf16 FLOP/s of the default device, or None if not tabled."""
+    return PEAK_BF16_FLOPS.get(jax.devices()[0].device_kind)
+
+
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
@@ -99,17 +110,11 @@ def block_choices(size: int, align: int, *, limit: int = 3) -> Tuple[int, ...]:
 
 
 def compiler_params(dimension_semantics: Optional[Tuple[str, ...]] = None):
-    """Version-tolerant TPU compiler params (ignored in interpret mode)."""
+    """Mosaic compiler params for a grid (ignored in interpret mode)."""
     if dimension_semantics is None:
         return None
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        try:
-            return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-        except (AttributeError, TypeError):
-            return pltpu.TPUCompilerParams(dimension_semantics=dimension_semantics)
-    except Exception:
-        return None
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
 def small_enough_off_tpu(*args, limit: int = 1 << 22) -> bool:

@@ -15,13 +15,15 @@ from .conv1d import conv1d_pallas
 def _conv1d_impl(x, w, bn, interpret):
     n, k = x.shape[0], w.shape[0]
     out_len = n - k + 1
+    hb = round_up(max(k - 1, 1), 128)            # halo: lane-aligned ≥ K-1
     bn = (pick_block(out_len, 1024, 128) if bn is None
           else clamp_block(bn, out_len, 128))
+    bn = round_up(bn, hb)
     out_pad = round_up(out_len, bn)
-    # signal must cover out_pad + k - 1 samples for the last tile's loads
-    xp = jnp.pad(x, (0, out_pad + k - 1 - n)).reshape(1, -1)
-    wp = w.reshape(1, -1)
-    out = conv1d_pallas(xp, wp, out_pad, bn=bn, interpret=interpret)
+    # the last tile's halo block ends at out_pad + hb (≥ n)
+    xp = jnp.pad(x, (0, out_pad + hb - n)).reshape(1, -1)
+    wp = w.astype(jnp.float32).reshape(1, -1)   # SMEM holds 32-bit scalars
+    out = conv1d_pallas(xp, wp, out_pad, bn=bn, hb=hb, interpret=interpret)
     return out[0, :out_len]
 
 

@@ -17,8 +17,11 @@ def _fft_impl(x, bm, bk, interpret):
     bm = pick_block(m, 128, 8) if bm is None else clamp_block(bm, m, 8)
     bk = pick_block(n, 512, 128) if bk is None else clamp_block(bk, n, 128)
     bn = pick_block(n, 256, 128)
-    t = jnp.arange(n, dtype=jnp.float32)
-    theta = (2.0 * jnp.pi / n) * jnp.outer(t, t)        # (time, freq)
+    # reduce t·k mod n in exact integers before scaling: an f32 angle of
+    # up to 2π·n would carry its rounding error into every twiddle
+    t = jnp.arange(n, dtype=jnp.int32)
+    theta = (2.0 * jnp.pi / n) * jnp.mod(jnp.outer(t, t), n).astype(
+        jnp.float32)                                    # (time, freq)
     c = jnp.cos(theta)
     s = -jnp.sin(theta)
     # time axis zero-pads exactly (0 · twiddle = 0); padded freq columns and

@@ -6,8 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..common import (LANE, block_choices, clamp_block, interpret_default,
-                      next_pow2, pad_dim, round_up)
+from ..common import (LANE, SUBLANE, block_choices, clamp_block,
+                      interpret_default, next_pow2, pad_dim, round_up)
 from .sorthist import hist_pallas, sort_pallas
 
 
@@ -57,10 +57,10 @@ def _hist_impl(x, bins, lo, hi, bk, interpret):
     # +inf padding falls outside [lo, hi] and is dropped by the kernel
     x2 = pad_dim(x.astype(jnp.float32).reshape(1, -1), 1, bk,
                  value=jnp.inf)
-    bpad = round_up(bins, LANE)
+    bpad = round_up(bins, SUBLANE)
     out = hist_pallas(x2, bins=bins, lo=lo, hi=hi, bpad=bpad, bk=bk,
                       interpret=interpret)
-    return out[0, :bins]
+    return out.sum(axis=1)[:bins]
 
 
 def hist(x, *, bins: int = 64, lo: float = 0.0, hi: float = 1.0,

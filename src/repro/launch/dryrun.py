@@ -250,20 +250,11 @@ def _with_repeats(cfg: ArchConfig, reps: Dict[int, int]) -> ArchConfig:
     return dataclasses.replace(cfg, stages=stages)
 
 
-def _cost_analysis(compiled) -> Dict[str, float]:
-    """compiled.cost_analysis() as a flat dict (older JAX returns a
-    one-element list of per-computation dicts)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def _cost_of(cfg: ArchConfig, shape: InputShape, mesh, ctx,
              microbatches: int) -> Dict[str, float]:
     lowered = lower_cell(cfg, shape, mesh, ctx, microbatches=microbatches)
     compiled = lowered.compile()
-    ca = _cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     colls = parse_collectives(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),
@@ -342,7 +333,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                 "alias_bytes": int(mem.alias_size_in_bytes),
                 "code_bytes": int(mem.generated_code_size_in_bytes),
             }
-            ca = _cost_analysis(compiled)
+            ca = compiled.cost_analysis() or {}
             rec["cost"] = {
                 "flops": float(ca.get("flops", 0.0)),
                 "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

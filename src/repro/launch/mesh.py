@@ -7,17 +7,11 @@ dry-run forces a 512-device host platform before first jax init.
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType only exists on newer JAX; older releases imply Auto axes
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the installed JAX has them."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types (sharding by propagation)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

@@ -21,6 +21,7 @@ from ..core.portability import ServeReport, percentile_nearest
 from ..models import build_model
 from ..serve.engine import (PagedEngine, RequestQueue, ServeEngine,
                             SlotEngine, StepScheduler)
+from .cache import use_compile_cache
 
 
 def main(argv=None):
@@ -52,6 +53,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.legacy and args.paged:
         ap.error("--legacy and --paged are mutually exclusive")
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -1,10 +1,10 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-On this CPU container it trains reduced/small configs end-to-end (the
-examples use it); on a real pod slice the same launcher drives the
-production mesh — the mesh/rules wiring, checkpointing, heartbeat, and
-straggler policy are identical in both modes (hardware-agnostic launch, the
-HALO property applied to the launcher).
+On a CPU it trains reduced/small configs end-to-end (the examples use
+it); on a TPU pod slice the same launcher drives the production mesh — the
+mesh/rules wiring, checkpointing, heartbeat, and straggler policy are
+identical in both modes (hardware-agnostic launch, the HALO property applied
+to the launcher).
 """
 from __future__ import annotations
 
@@ -21,6 +21,7 @@ from ..models import build_model
 from ..train.checkpoint import CheckpointManager
 from ..train.fault_tolerance import HeartbeatJournal, StragglerPolicy
 from ..train.trainer import TrainHyper, Trainer
+from .cache import use_compile_cache
 from .mesh import make_debug_mesh, make_production_mesh
 
 
@@ -45,6 +46,7 @@ def main(argv=None):
                     default="none")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

@@ -2,8 +2,8 @@
 HOST:PORT [--name w0] [--platforms xla,jnp] [--devices N]``.
 
 Spawned by :func:`repro.distributed.remote.spawn_worker`, which puts
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (and usually
-``JAX_PLATFORMS=cpu``) in this process's environment *before* it starts —
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` and
+``JAX_PLATFORMS=cpu`` in this process's environment *before* it starts —
 the flag only takes effect ahead of jax initialization, which is why
 workers are fresh processes rather than forks.  The heavy imports happen
 inside :func:`main` so ``--help`` and argument errors stay instant.

@@ -39,14 +39,9 @@ Params = Dict[str, jax.Array]
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """jax.shard_map with replication checking off, on any supported JAX
-    (older releases ship it as jax.experimental.shard_map with check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """jax.shard_map with replication checking off."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def moe_param_specs(d_model: int, m: MoEConfig, dtype) -> Dict[str, ParamSpec]:

@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig, AttnConfig, BlockSpec, Stage
-from ..distributed.sharding import ParamSpec, current_context, shard
+from ..distributed.sharding import (ParamSpec, current_context,
+                                    named_sharding, shard)
 from .attention import attn_param_specs, gqa_forward, mla_forward
 from .layers import (embed_tokens, ffn, logits_from_hidden, rms_norm,
                      softmax_xent)
@@ -99,10 +100,12 @@ def param_specs(cfg: ArchConfig) -> PyTree:
 
 
 def init_params(cfg: ArchConfig, key: jax.Array) -> PyTree:
+    """Random parameters, made by one jitted program.  Under a mesh context
+    every leaf is created already sharded by its ParamSpec's logical axes,
+    so no device ever holds the whole model."""
     specs = param_specs(cfg)
     leaves, treedef = jax.tree.flatten(
         specs, is_leaf=lambda x: isinstance(x, ParamSpec))
-    keys = jax.random.split(key, len(leaves))
 
     def materialize(s: ParamSpec, k):
         if s.init_kind == "ones":
@@ -121,8 +124,16 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> PyTree:
         w = jax.random.normal(k, s.shape, jnp.float32) * (fan_in ** -0.5)
         return w.astype(s.dtype)
 
-    vals = [materialize(s, k) for s, k in zip(leaves, keys)]
-    return jax.tree.unflatten(treedef, vals)
+    def build(key):
+        keys = jax.random.split(key, len(leaves))
+        return jax.tree.unflatten(
+            treedef, [materialize(s, k) for s, k in zip(leaves, keys)])
+
+    shardings = None
+    if current_context().mesh is not None:
+        shardings = jax.tree.unflatten(
+            treedef, [named_sharding(s.shape, s.logical) for s in leaves])
+    return jax.jit(build, out_shardings=shardings)(key)
 
 
 # ---------------------------------------------------------------------------

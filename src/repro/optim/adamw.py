@@ -20,7 +20,7 @@ class AdamWState(NamedTuple):
 
 
 def adamw_init(params: PyTree) -> AdamWState:
-    zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
+    zeros = lambda p: jnp.zeros_like(p, dtype=jnp.float32)   # keeps sharding
     return AdamWState(step=jnp.zeros((), jnp.int32),
                       mu=jax.tree.map(zeros, params),
                       nu=jax.tree.map(zeros, params))

@@ -192,6 +192,30 @@ def test_selection_respects_supports_predicate(agent):
     assert rec.platform == "xla"
 
 
+def test_smmm_pallas_record_takes_int_indices(agent, rng):
+    from repro.kernels.spmm import dense_to_bell, random_block_sparse
+    vals, idx = dense_to_bell(random_block_sparse(rng, 128, 128, 32, 128),
+                              32, 128)
+    b = jnp.ones((128, 16))
+    cands = agent.registry.candidates("SMMM", vals, idx, b,
+                                      allowed_platforms=["pallas"])
+    assert [r.platform for r in cands] == ["pallas"]
+
+
+@pytest.mark.parametrize("shape,pallas", [((1,), True), ((2, 2), False)])
+def test_pallas_records_step_aside_under_multi_device_mesh(agent, shape,
+                                                          pallas):
+    """Mosaic kernels cannot be partitioned automatically: model code
+    tracing under a multi-device mesh gets the xla records."""
+    from repro.distributed.sharding import mesh_context
+    a = jnp.ones((16, 16))
+    axes = ("data", "model")[:len(shape)]
+    with mesh_context(jax.sharding.AbstractMesh(shape, axes)):
+        plats = {r.platform for r in agent.registry.candidates(
+            "MMM", a, a, allowed_platforms=["pallas", "xla"])}
+    assert plats == ({"pallas", "xla"} if pallas else {"xla"})
+
+
 def test_sw_fid_lookup(agent, rng):
     """Resources resolve by sw_fid as well as alias (Table I/II)."""
     a = jax.random.normal(rng, (8, 8))
@@ -235,6 +259,27 @@ def test_plug_and_play_register_deregister(agent, rng):
     cr2 = agent.claim("MMM")
     agent.send((a, a), cr2)
     np.testing.assert_allclose(agent.recv(cr2), a @ a)
+
+
+def test_sharded_agent_executes_under_its_mesh(agent, rng):
+    """A record placed on the sharded substrate runs inside the agent's
+    mesh (jax.set_mesh) and is not quarantined."""
+    from repro.launch.mesh import make_mesh
+    agent.attach_mesh(make_mesh((1,), ("data",)))
+    seen = []
+
+    def add_under_mesh(a, b):
+        seen.append(jax.sharding.get_abstract_mesh().axis_names)
+        return a + b
+
+    agent.registry.register(KernelRecord(
+        alias="EWADD", fn=add_under_mesh, platform="sharded", priority=99))
+    a = jax.random.normal(rng, (8, 8))
+    cr = agent.claim("EWADD", overrides={"allowed_platforms": ["sharded"]})
+    agent.send((a, a), cr)
+    np.testing.assert_allclose(agent.recv(cr), 2 * a)
+    assert seen == [("data",)]
+    assert agent.scheduler.failed_record_keys() == []
 
 
 def test_internal_buffers_stateful(agent):

@@ -222,6 +222,23 @@ def test_wire_cache_unpinned_ref_rejected():
 
 
 # ---------------------------------------------------------------------------
+def test_spawned_worker_always_runs_jax_on_cpu(monkeypatch):
+    """A worker never inherits an accelerator platform: the parent that
+    touched JAX holds the chip, so a child asking for it fails or hangs."""
+    from repro.distributed import remote
+    seen = {}
+
+    def popen(cmd, env):
+        seen.update(env)
+        raise OSError("not started")
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(remote.subprocess, "Popen", popen)
+    with pytest.raises(OSError):
+        spawn_worker("tw-env", devices=1, env={"JAX_PLATFORMS": "tpu"})
+    assert seen["JAX_PLATFORMS"] == "cpu"
+
+
 # Live worker fixtures
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
