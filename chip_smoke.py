@@ -33,6 +33,7 @@ import argparse
 import dataclasses
 import gc
 import json
+import math
 import os
 import sys
 import time
@@ -296,15 +297,13 @@ def train_losses(cfg, *, steps: int = 3, batch: int = 8, seq: int = 512,
     log(f"train ({'mesh ' + str(dict(mesh.shape)) if mesh else '1 device'}):"
         f" losses {losses}, wall {dt:.1f}s for {steps} steps "
         f"(compile included)")
-    if len(losses) != steps or not all(map(_finite, losses)):
+    if len(losses) != steps or not all(map(math.isfinite, losses)):
         fail(f"training loss not finite: {losses}")
     return losses
 
 
 def _check_spread(params, n: int) -> None:
     """Every layer matrix is split ``n`` ways, one shard per device."""
-    import math
-
     import jax
 
     def shard_size(x):
@@ -320,10 +319,6 @@ def _check_spread(params, n: int) -> None:
     log(f"train: {len(layer)} layer weights each split {n} ways; param "
         f"bytes per device {sum(shard_size(x) * x.dtype.itemsize for x in leaves)}"
         f" of {sum(x.nbytes for x in leaves)}")
-
-
-def _finite(x: float) -> bool:
-    return x == x and abs(x) != float("inf")
 
 
 def phase_sharded(cfg, **kw) -> None:

@@ -51,23 +51,20 @@ def _unpartitioned() -> bool:
     return mesh is None or mesh.size == 1
 
 
-def _pallas_supports(supports):
-    if supports is None:
-        return lambda *args, **kw: _unpartitioned()
-    return lambda *args, **kw: _unpartitioned() and supports(*args, **kw)
-
-
 def _rec(alias, fn, platform, prio, *, failsafe=False, supports=None,
          cost=None, space=None, doc=""):
     hw = _TPU_ATTRS if platform == "pallas" else _ANY_ATTRS
     if platform == "pallas":
-        supports = _pallas_supports(supports)
-    if platform == "pallas" and jax.default_backend() != "tpu":
-        # Table-II cost models are per-hardware attributes calibrated for
-        # the TPU target; off-TPU the pallas records run in interpret mode
-        # (a validation vehicle), where the analytic estimate is off by
-        # orders of magnitude and would hijack latency-aware placement.
-        cost = None
+        feasible = supports or (lambda *args, **kw: True)
+        supports = lambda *args, **kw: (_unpartitioned()
+                                        and feasible(*args, **kw))
+        if jax.default_backend() != "tpu":
+            # Table-II cost models are per-hardware attributes calibrated
+            # for the TPU target; off-TPU the pallas records run in
+            # interpret mode (a validation vehicle), where the analytic
+            # estimate is off by orders of magnitude and would hijack
+            # latency-aware placement.
+            cost = None
     return KernelRecord(
         alias=alias, fn=fn, platform=platform, priority=prio,
         attrs=KernelAttributes(sw_fid=f"fid:{alias.lower()}", **hw),

@@ -221,7 +221,6 @@ def test_wire_cache_unpinned_ref_rejected():
                        [], {})
 
 
-# ---------------------------------------------------------------------------
 def test_spawned_worker_always_runs_jax_on_cpu(monkeypatch):
     """A worker never inherits an accelerator platform: the parent that
     touched JAX holds the chip, so a child asking for it fails or hangs."""
@@ -239,6 +238,7 @@ def test_spawned_worker_always_runs_jax_on_cpu(monkeypatch):
     assert seen["JAX_PLATFORMS"] == "cpu"
 
 
+# ---------------------------------------------------------------------------
 # Live worker fixtures
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
