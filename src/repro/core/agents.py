@@ -750,6 +750,7 @@ class RuntimeAgent:
             collections.OrderedDict()
         self._lock = threading.RLock()
         self.finalized = False
+        self._mesh_demotion_logged = False
         # T1 instrumentation: host-side dispatch overhead accounting
         self._t1_seconds = 0.0
         self._t1_calls = 0
@@ -882,7 +883,21 @@ class RuntimeAgent:
             self.attach_agent(ShardedAgent(mesh))
 
     def _allowed_platforms(self) -> List[str]:
-        return [p for p, a in self.agents.items() if a.available()]
+        """Available substrates.  Under a multi-device mesh (model code
+        tracing inside ``mesh_context``) the pallas substrate steps aside:
+        the SPMD partitioner cannot split a Mosaic kernel, so the
+        partitionable xla records serve every alias there."""
+        from ..distributed.sharding import current_context
+        allowed = [p for p, a in self.agents.items() if a.available()]
+        mesh = current_context().mesh
+        if mesh is not None and mesh.size > 1 and "pallas" in allowed:
+            if not self._mesh_demotion_logged:
+                self._mesh_demotion_logged = True
+                log.warning("pallas records step aside under the %s-device "
+                            "mesh %s: Mosaic kernels cannot be partitioned",
+                            mesh.size, dict(mesh.shape))
+            allowed.remove("pallas")
+        return allowed
 
     def _platform_preference(self) -> Optional[Sequence[str]]:
         """Hardware recommendation strategy (paper §IV-C, platform_list).

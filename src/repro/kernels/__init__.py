@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import GLOBAL_REGISTRY, KernelAttributes, KernelRecord
-from ..distributed.sharding import current_context
 from .common import peak_bf16_flops, small_enough_off_tpu
 
 _REGISTERED = False
@@ -43,28 +42,15 @@ def _ewise_ok(*args, **kw) -> bool:
             and _pallas_ok(*args, **kw))
 
 
-def _unpartitioned() -> bool:
-    """True unless model code is tracing under a multi-device mesh: the SPMD
-    partitioner cannot split a Mosaic kernel, so there the pallas records
-    step aside and the partitionable xla records serve the alias."""
-    mesh = current_context().mesh
-    return mesh is None or mesh.size == 1
-
-
 def _rec(alias, fn, platform, prio, *, failsafe=False, supports=None,
          cost=None, space=None, doc=""):
     hw = _TPU_ATTRS if platform == "pallas" else _ANY_ATTRS
-    if platform == "pallas":
-        feasible = supports or (lambda *args, **kw: True)
-        supports = lambda *args, **kw: (_unpartitioned()
-                                        and feasible(*args, **kw))
-        if jax.default_backend() != "tpu":
-            # Table-II cost models are per-hardware attributes calibrated
-            # for the TPU target; off-TPU the pallas records run in
-            # interpret mode (a validation vehicle), where the analytic
-            # estimate is off by orders of magnitude and would hijack
-            # latency-aware placement.
-            cost = None
+    if platform == "pallas" and jax.default_backend() != "tpu":
+        # Table-II cost models are per-hardware attributes calibrated for
+        # the TPU target; off-TPU the pallas records run in interpret mode
+        # (a validation vehicle), where the analytic estimate is off by
+        # orders of magnitude and would hijack latency-aware placement.
+        cost = None
     return KernelRecord(
         alias=alias, fn=fn, platform=platform, priority=prio,
         attrs=KernelAttributes(sw_fid=f"fid:{alias.lower()}", **hw),

@@ -24,6 +24,22 @@ from ..serve.engine import (PagedEngine, RequestQueue, ServeEngine,
 from .cache import use_compile_cache
 
 
+def workload(cfg, *, seed: int, requests: int, prompt_len: int,
+             max_new: int):
+    """The launcher's request mix: ``requests`` random prompts of
+    ``prompt_len`` tokens drawn from ``seed``, their decode budgets, and
+    the per-lane cache length that holds the longest request."""
+    rng = jax.random.split(jax.random.PRNGKey(seed), requests)
+    prompts = [list(map(int, jax.random.randint(
+        rng[i], (prompt_len,), 0, cfg.vocab_size)))
+        for i in range(requests)]
+    # mixed decode budgets: slot lanes retire independently, the legacy
+    # path runs every request to the live batch max
+    max_news = [max(1, max_new - (i % 4) * (max_new // 4))
+                for i in range(requests)]
+    return prompts, max_news, prompt_len + max_new + cfg.prefix_len + 8
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -59,18 +75,10 @@ def main(argv=None):
     if args.reduced:
         cfg = cfg.reduced()
     model = build_model(cfg)
-    key = jax.random.PRNGKey(args.seed)
-    params = model.init(key)
-    max_len = args.prompt_len + args.max_new + cfg.prefix_len + 8
-
-    rng = jax.random.split(key, args.requests)
-    prompts = [list(map(int, jax.random.randint(
-        rng[i], (args.prompt_len,), 0, cfg.vocab_size)))
-        for i in range(args.requests)]
-    # mixed decode budgets: slot lanes retire independently, the legacy
-    # path runs every request to the live batch max
-    max_news = [max(1, args.max_new - (i % 4) * (args.max_new // 4))
-                for i in range(args.requests)]
+    params = model.init(jax.random.PRNGKey(args.seed))
+    prompts, max_news, max_len = workload(
+        cfg, seed=args.seed, requests=args.requests,
+        prompt_len=args.prompt_len, max_new=args.max_new)
 
     sched = None
     paged = None

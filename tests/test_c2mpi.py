@@ -204,16 +204,22 @@ def test_smmm_pallas_record_takes_int_indices(agent, rng):
 
 @pytest.mark.parametrize("shape,pallas", [((1,), True), ((2, 2), False)])
 def test_pallas_records_step_aside_under_multi_device_mesh(agent, shape,
-                                                          pallas):
+                                                          pallas, caplog):
     """Mosaic kernels cannot be partitioned automatically: model code
-    tracing under a multi-device mesh gets the xla records."""
+    tracing under a multi-device mesh gets the xla records, and the
+    runtime says so once."""
     from repro.distributed.sharding import mesh_context
     a = jnp.ones((16, 16))
     axes = ("data", "model")[:len(shape)]
     with mesh_context(jax.sharding.AbstractMesh(shape, axes)):
+        for _ in range(2):
+            allowed = agent._allowed_platforms()
         plats = {r.platform for r in agent.registry.candidates(
-            "MMM", a, a, allowed_platforms=["pallas", "xla"])}
-    assert plats == ({"pallas", "xla"} if pallas else {"xla"})
+            "MMM", a, a, allowed_platforms=allowed)}
+    assert plats == ({"pallas", "xla", "jnp"} if pallas else {"xla", "jnp"})
+    assert "pallas" in agent._allowed_platforms()
+    notes = [r for r in caplog.records if "step aside" in r.getMessage()]
+    assert len(notes) == (0 if pallas else 1)
 
 
 def test_sw_fid_lookup(agent, rng):

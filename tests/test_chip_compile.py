@@ -16,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.conv1d import conv1d
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.fused import ACC, ewise_chain
 from repro.kernels.matmul import mmm
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.sorthist import hist, sort
@@ -38,6 +39,10 @@ CASES = {
                [((1 << 22,), F32), ((17,), F32)]),
     "SORT": (lambda x: sort(x, interpret=False), [((64, 4096), F32)]),
     "HIST": (lambda x: hist(x, interpret=False), [((1 << 20,), F32)]),
+    # the fusion pass's generated kernel for an EWMM -> EWADD chain
+    "FUSED_EWISE": (lambda a, b, c: ewise_chain(
+        a, b, c, steps=(("mul", 0, 1), ("add", ACC, 2)), interpret=False),
+        [((8192, 8192), F32)] * 3),
 }
 
 
