@@ -1,0 +1,16 @@
+"""The MMM kernel's roofline share inside the decode program: the least
+time its calls need at their call shapes (per call the larger of
+operations at peak and bytes at peak bandwidth) over the device time of the
+MMM kernel's events in that program."""
+import devtrace as trace
+
+
+def read(ctx):
+    s, calls = ctx.get("trace"), ctx.get("calls") or {}
+    prog = "jit__decode_fn"
+    if not s or prog not in calls:
+        return None
+    t = trace.kernel_time(s, "_mmm_impl", {prog})
+    if t <= 0:
+        return None
+    return 100.0 * calls[prog]["roof_s_mean"] * s["module_n"].get(prog, 0) / t
