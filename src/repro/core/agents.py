@@ -439,8 +439,9 @@ class HealthMonitor:
 class VirtualizationAgent:
     """Encapsulates one execution substrate behind the C2MPI accelerator
     interface.  The paper's three-stage pipeline (network manager → system
-    services → device services) maps to ``_ingest`` → ``_services`` →
-    ``_device_execute``."""
+    services → device services) maps to ``_ingest`` → ``_device_execute``:
+    in one process no request is resolvable without the device, so the
+    system-services stage has no work."""
 
     platform: str = "jnp"
 
@@ -597,14 +598,6 @@ class VirtualizationAgent:
     def _ingest(self, record: KernelRecord, args: Tuple, kwargs: Dict):
         return args, kwargs
 
-    # stage 2: system services — requests resolvable without hardware
-    def _services(self, record: KernelRecord, args: Tuple):
-        with self._lock:
-            self.metrics["requests"] += 1
-            for a in args:
-                if hasattr(a, "nbytes"):
-                    self.metrics["bytes_in"] += int(a.nbytes)
-
     # stage 3: device services — vendor logic / device manager
     def _device_execute(self, record: KernelRecord, args: Tuple, kwargs: Dict):
         return record.fn(*args, **kwargs)
@@ -614,7 +607,6 @@ class VirtualizationAgent:
 
     def execute(self, record: KernelRecord, *args, **kwargs):
         args, kwargs = self._ingest(record, args, kwargs)
-        self._services(record, args)
         out = self._device_execute(record, args, kwargs)
         with self._lock:
             self.metrics["completed"] += 1
@@ -1076,7 +1068,12 @@ class RuntimeAgent:
             raise
         finally:
             self._account_t1(time.perf_counter() - t0)
-        return record.fn(*args, **self._tuned_kwargs(record, args, kwargs))
+        # every op the record emits carries halo.<alias> in its op_name
+        # metadata, so a device trace can tell the runtime's kernels from
+        # the glue around them, whichever record serves the alias
+        with jax.named_scope(f"halo.{alias}"):
+            return record.fn(*args, **self._tuned_kwargs(record, args,
+                                                         kwargs))
 
     def _execute_on(self, agent: VirtualizationAgent, record: KernelRecord,
                     cr: Optional[ChildRank], args: Tuple, kwargs: Dict):

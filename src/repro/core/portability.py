@@ -123,11 +123,18 @@ class ServeReport:
     """Serving-path scorecard: the paper's T-term decomposition applied to
     the slot engine's iteration loop (DESIGN.md §6).
 
-    T1 = host orchestration (admission bookkeeping, slot retirement, RNG and
-    mask assembly), T3 = blocked device time (prefill-into-slot + batched
-    decode step execution), T2 ≈ 0 (the slot cache is device-resident
-    between iterations).  ``overhead`` is the paper's T1/T4 — the serving
-    path reports the same scorecard as the kernel path (Table VIII)."""
+    Both terms are host wall time, read from the scheduler's span totals
+    (:mod:`repro.core.tracing`).  T3 is the host time inside the engine's
+    device calls: the ``serve.admit``, ``serve.chunk`` and ``serve.decode``
+    spans.  A call that returns a token waits for the device; a prefill
+    chunk that returns none only dispatches its program, whose device time
+    then lands in the next call that waits.  T1 is the rest of
+    each iteration's ``serve.step`` (admission bookkeeping, slot retirement,
+    RNG and mask assembly, token streaming).  T2 ≈ 0 (the cache is
+    device-resident between iterations).  The device's own busy time is not
+    in either term: it comes from a profiler trace.  ``overhead`` is the
+    paper's T1/T4 — the serving path reports the same scorecard as the
+    kernel path (Table VIII)."""
 
     t1_s: float
     t3_s: float

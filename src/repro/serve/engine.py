@@ -13,8 +13,9 @@ Three layers:
   independently on per-request EOS or ``max_new`` — requests join and leave
   mid-flight with no echo padding and no batch-max coupling.  ``submit``
   returns a :class:`~repro.core.agents.HaloFuture` immediately, with
-  per-token streaming hooks; per-iteration host time (T1) and blocked device
-  time (T3) accumulate into the same scorecard the kernel path reports
+  per-token streaming hooks.  Host spans (``serve.*``,
+  :mod:`repro.core.tracing`) time each iteration and its admission, chunk
+  and decode calls; their totals give the scorecard the kernel path reports
   (:class:`~repro.core.portability.ServeReport`).
 * :class:`ServeEngine` / :class:`RequestQueue` — the legacy whole-batch
   front, kept as a thin compat wrapper over the slot engine: batch
@@ -38,6 +39,7 @@ import numpy as np
 
 from ..core.agents import AgentDeadError, AgentState, HaloFuture
 from ..core.portability import ServeReport
+from ..core.tracing import Spans
 from ..models.transformer import Model
 from .kvcache import (BlockPool, LeafSpec, NoFreeBlocks, _is_spec,
                       copy_block, evict_slot, gather_views, init_paged,
@@ -144,6 +146,7 @@ class SlotEngine:
         self.slots = slots
         self.max_len = max_len
         self.caches = model.init_cache(slots, max_len)
+        self.spans = Spans()
         self._admit = jax.jit(self._admit_fn, donate_argnums=(1,))
         self._decode = jax.jit(self._decode_fn, donate_argnums=(1,))
         self._evict = jax.jit(evict_slot, donate_argnums=(0,))
@@ -178,11 +181,14 @@ class SlotEngine:
         ``tok``/``pos``/``active`` are host (B,) arrays; returns the host
         (B,) next-token array (entries for inactive lanes are garbage —
         their cache writes were masked out by ``active``)."""
-        self.caches, nxt = self._decode(
-            self.params, self.caches, jnp.asarray(tok, jnp.int32)[:, None],
-            jnp.asarray(pos, jnp.int32), jnp.asarray(active, bool), key,
-            float(temperature))
-        return jax.device_get(nxt)
+        with self.spans.span("serve.decode.call"):
+            self.caches, nxt = self._decode(
+                self.params, self.caches,
+                jnp.asarray(tok, jnp.int32)[:, None],
+                jnp.asarray(pos, jnp.int32), jnp.asarray(active, bool), key,
+                float(temperature))
+        with self.spans.span("serve.decode.wait"):
+            return jax.device_get(nxt)
 
     def release_slot(self, slot: int) -> None:
         """Zero a retired lane (see kvcache.evict_slot)."""
@@ -299,6 +305,7 @@ class PagedEngine:
         self.tables = np.zeros((slots, self.blocks_per_lane), np.int32)
         self._meta: List[Optional[_SlotMeta]] = [None] * slots
         self.tokens_cached = 0          # positions written (prompt + decode)
+        self.spans = Spans()
         self._admit = jax.jit(self._admit_fn, donate_argnums=(1,))
         self._chunk = jax.jit(self._chunk_fn, donate_argnums=(1,))
         self._decode = jax.jit(self._decode_fn, donate_argnums=(1,))
@@ -522,17 +529,20 @@ class PagedEngine:
         crosses a block boundary, then COW-fence the write set — after
         which every block written this step is private, so the jitted
         gather → decode → scatter touches no shared storage."""
-        for i, on in enumerate(active):
-            if on:
-                p = int(pos[i])
-                self._grow_table(i, p + 1)
-                self._prepare_writes(i, p, 1)
-                self.tokens_cached += 1
-        self.paged, nxt = self._decode(
-            self.params, self.paged, jnp.asarray(tok, jnp.int32)[:, None],
-            jnp.asarray(self.tables), jnp.asarray(pos, jnp.int32),
-            jnp.asarray(active, bool), key, float(temperature))
-        return jax.device_get(nxt)
+        with self.spans.span("serve.decode.prep"):
+            for i, on in enumerate(active):
+                if on:
+                    p = int(pos[i])
+                    self._grow_table(i, p + 1)
+                    self._prepare_writes(i, p, 1)
+                    self.tokens_cached += 1
+        with self.spans.span("serve.decode.call"):
+            self.paged, nxt = self._decode(
+                self.params, self.paged, jnp.asarray(tok, jnp.int32)[:, None],
+                jnp.asarray(self.tables), jnp.asarray(pos, jnp.int32),
+                jnp.asarray(active, bool), key, float(temperature))
+        with self.spans.span("serve.decode.wait"):
+            return jax.device_get(nxt)
 
     def release_slot(self, slot: int) -> None:
         """Host-only retirement: deref the lane's chain and return its
@@ -595,13 +605,24 @@ class _Lane:
 # ---------------------------------------------------------------------------
 # Step scheduler: admission / step / retirement loop
 # ---------------------------------------------------------------------------
+#: the spans in which the scheduler is blocked in the engine's device calls
+#: (ServeReport's T3); they never nest
+_DEVICE_CALL_SPANS = ("serve.admit", "serve.chunk", "serve.decode")
+
+
 class StepScheduler:
     """Continuous-batching loop over a :class:`SlotEngine` (DESIGN.md §6).
 
     ``submit`` returns a future immediately; requests are admitted into free
     slots mid-flight and retire independently on their own EOS or
     ``max_new``.  Drive the loop synchronously (``step``/``drain``) or in
-    the background (``start``/``stop``, or ``with sched:``)."""
+    the background (``start``/``stop``, or ``with sched:``).
+
+    ``spans`` (shared with the engine) times each iteration ``serve.step``,
+    each admission ``serve.admit``, each prefill chunk the loop runs
+    ``serve.chunk`` and each decode step ``serve.decode`` (its parts
+    ``serve.decode.prep``/``.call``/``.wait`` in the engine); the counter
+    ``serve.decode_lanes`` sums the lanes of every decode step."""
 
     _seq = itertools.count(1)
 
@@ -627,9 +648,10 @@ class StepScheduler:
         # scheduler instance is shared (see ServeEngine.generate)
         self.drive_lock = threading.Lock()
         self.completed = 0
-        # T1/T3 scorecard accumulators (core.portability.ServeReport)
-        self._t1 = 0.0
-        self._t3 = 0.0
+        self.spans = getattr(engine, "spans", None) or Spans()
+        # scorecard (core.portability.ServeReport): the span totals as the
+        # last iteration ended, read against this baseline
+        self._totals = self._base = self.spans.snapshot()
         self._steps = 0
         self._tokens = 0
 
@@ -736,11 +758,18 @@ class StepScheduler:
         self._fail_active(exc)
 
     def report(self) -> ServeReport:
-        return ServeReport(t1_s=self._t1, t3_s=self._t3, steps=self._steps,
-                           tokens=self._tokens)
+        """T1/T3 since construction or ``reset_stats``, from the span
+        totals as the last iteration ended (so a step in flight counts in
+        neither): T3 is the time in ``serve.admit``, ``serve.chunk`` and
+        ``serve.decode``, T1 the rest of ``serve.step`` (ServeReport says
+        what each holds)."""
+        spent = self.spans.since(self._base, self._totals)["seconds"]
+        t3 = sum(spent.get(name, 0.0) for name in _DEVICE_CALL_SPANS)
+        return ServeReport(t1_s=spent.get("serve.step", 0.0) - t3, t3_s=t3,
+                           steps=self._steps, tokens=self._tokens)
 
     def reset_stats(self) -> None:
-        self._t1 = self._t3 = 0.0
+        self._base = self._totals
         self._steps = self._tokens = 0
 
     # -- engine iteration ----------------------------------------------------
@@ -834,8 +863,13 @@ class StepScheduler:
 
         Returns True if any work was done.  Call from a single thread at a
         time (the background loop, or the caller when not started)."""
-        t0 = time.perf_counter()
-        dev = 0.0
+        try:
+            with self.spans.span("serve.step"):
+                return self._step()
+        finally:
+            self._totals = self.spans.snapshot()
+
+    def _step(self) -> bool:
         worked = False
         self._beat()          # claim the iteration: a hang inside it stalls
         self._expire_queued()
@@ -856,22 +890,23 @@ class StepScheduler:
             slot = free[0]
             worked = True
             req.started_at = time.monotonic()
-            d0 = time.perf_counter()
             try:
-                if begin is not None:
-                    with self._cond:
-                        # hold the slot before the device call: a chunked
-                        # admission spans iterations
-                        self._lanes[slot] = _Lane(req, pos=0, last_tok=-1,
-                                                  tokens=[],
-                                                  prefilling=True)
-                    tok = begin(slot, req.prompt, req.max_new,
-                                self._next_key(), self.temperature)
-                else:
-                    tok = self.engine.prefill_into_slot(
-                        slot, req.prompt, self._next_key(), self.temperature)
+                with self.spans.span("serve.admit", uid=req.uid,
+                                     prompt_len=len(req.prompt)):
+                    if begin is not None:
+                        with self._cond:
+                            # hold the slot before the device call: a
+                            # chunked admission spans iterations
+                            self._lanes[slot] = _Lane(req, pos=0,
+                                                      last_tok=-1, tokens=[],
+                                                      prefilling=True)
+                        tok = begin(slot, req.prompt, req.max_new,
+                                    self._next_key(), self.temperature)
+                    else:
+                        tok = self.engine.prefill_into_slot(
+                            slot, req.prompt, self._next_key(),
+                            self.temperature)
             except Exception as exc:
-                dev += time.perf_counter() - d0
                 with self._cond:
                     self._lanes[slot] = None
                 self._abandon(slot)
@@ -882,7 +917,6 @@ class StepScheduler:
                     # in-flight lane lost its cache state
                     self._fail_active(exc)
                 continue
-            dev += time.perf_counter() - d0
             if tok is None:
                 continue           # chunked prefill in flight on this lane
             with self._cond:
@@ -896,11 +930,14 @@ class StepScheduler:
                           if l is not None and l.prefilling]
         for i, lane in prefilling:
             worked = True
-            d0 = time.perf_counter()
             try:
-                tok = self.engine.continue_admission(i)
+                meta = self.engine._meta[i]
+                with self.spans.span(
+                        "serve.chunk", uid=lane.req.uid, start=meta.pos,
+                        chunk_len=min(self.engine.chunk_tokens,
+                                      len(meta.prompt) - meta.pos)):
+                    tok = self.engine.continue_admission(i)
             except Exception as exc:
-                dev += time.perf_counter() - d0
                 with self._cond:
                     self._lanes[i] = None
                 self._abandon(i)
@@ -909,7 +946,6 @@ class StepScheduler:
                 if not self.engine.ensure_caches():
                     self._fail_active(exc)
                 continue
-            dev += time.perf_counter() - d0
             if tok is None:
                 continue                     # more chunks to go
             with self._cond:
@@ -928,18 +964,16 @@ class StepScheduler:
             act = np.zeros((b,), bool)
             for i, lane in occupied:
                 tok[i], pos[i], act[i] = lane.last_tok, lane.pos, True
-            d0 = time.perf_counter()
+            self.spans.count("serve.decode_lanes", len(occupied))
             try:
-                nxt = self.engine.decode_step(tok, pos, act, self._next_key(),
-                                              self.temperature)
+                with self.spans.span("serve.decode", lanes=len(occupied)):
+                    nxt = self.engine.decode_step(tok, pos, act,
+                                                  self._next_key(),
+                                                  self.temperature)
             except Exception as exc:
-                dev += time.perf_counter() - d0
                 self._fail_active(exc)
                 self.engine.ensure_caches()   # rebuild if donation consumed
-                self._t3 += dev
-                self._t1 += (time.perf_counter() - t0) - dev
                 raise
-            dev += time.perf_counter() - d0
 
             # (c) retirement: each slot checks its own EOS / max_new
             for i, lane in occupied:
@@ -959,8 +993,6 @@ class StepScheduler:
         if worked:
             self._steps += 1
             self._beat()
-        self._t3 += dev
-        self._t1 += (time.perf_counter() - t0) - dev
         return worked
 
     def drain(self) -> None:

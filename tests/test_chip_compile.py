@@ -8,12 +8,14 @@ at the sizes ``chip_smoke.py`` runs and compile it for one chip of a
 emits the Mosaic kernel as a ``tpu_custom_call``.  Nothing runs.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.c2mpi import halo_dispatch
 from repro.kernels.conv1d import conv1d
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused import ACC, ewise_chain
@@ -80,3 +82,27 @@ def test_kernel_compiles_for_v5e(alias, one_chip, no_persistent_cache):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dispatch_scope_keeps_the_kernel_name(one_chip, no_persistent_cache):
+    """``halo_dispatch`` wraps each record in a ``halo.<ALIAS>`` scope: the
+    Pallas MMM's custom call keeps its instruction name ``_mmm_impl`` (what
+    a device trace finds it by) and carries the scope in its op_name."""
+    pin = {"allowed_platforms": ("pallas",)}
+
+    def step(x, w, g):
+        y = halo_dispatch("MMM", x, w, overrides=pin, interpret=False)
+        return halo_dispatch("RMSNORM", y[None], g, eps=1e-5, overrides=pin,
+                             interpret=False)
+
+    # under the interpret-mode size cap, so the Pallas records are feasible
+    # on this host's backend
+    shapes = [(8, 2560), (2560, 1280), (1280,)]
+    args = [jax.ShapeDtypeStruct(s, BF16, sharding=one_chip) for s in shapes]
+    text = jax.jit(step).lower(*args).compile().as_text()
+    for alias, name in (("MMM", "_mmm_impl"), ("RMSNORM", "_rmsnorm_impl")):
+        calls = [ln for ln in text.splitlines()
+                 if re.match(rf"\s*%{name}(\.\d+)? = ", ln)]
+        assert calls and all("tpu_custom_call" in ln for ln in calls), name
+        assert all(f'op_name="jit(step)/halo.{alias}/' in ln
+                   for ln in calls), name

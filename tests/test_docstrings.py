@@ -16,6 +16,7 @@ MODULES = [
     "repro.core.graph",
     "repro.core.registry",
     "repro.core.scheduler",
+    "repro.core.tracing",
     "repro.core.tuning",
     "repro.testing.faults",
 ]
