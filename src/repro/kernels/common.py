@@ -68,6 +68,17 @@ def pick_block(size: int, preferred: int, align: int) -> int:
     return max(align, round_up(size, align))
 
 
+def divisor_block(size: int, preferred: int, align: int) -> int:
+    """Largest aligned block ≤ preferred that divides ``size``, so the
+    operand needs no padding; :func:`pick_block`'s choice when ``size`` is
+    not a multiple of ``align``."""
+    if size % align == 0:
+        for b in range(min(preferred, size) // align * align, 0, -align):
+            if size % b == 0:
+                return b
+    return pick_block(size, preferred, align)
+
+
 def next_pow2(n: int) -> int:
     """Smallest power of two ≥ ``n`` (1 for n ≤ 1)."""
     return 1 if n <= 1 else 1 << (int(n) - 1).bit_length()
@@ -109,12 +120,18 @@ def block_choices(size: int, align: int, *, limit: int = 3) -> Tuple[int, ...]:
     return tuple(out)
 
 
-def compiler_params(dimension_semantics: Optional[Tuple[str, ...]] = None):
-    """Mosaic compiler params for a grid (ignored in interpret mode)."""
+def compiler_params(dimension_semantics: Optional[Tuple[str, ...]] = None,
+                    allow_input_fusion: Optional[Tuple[bool, ...]] = None):
+    """Mosaic compiler params for a grid (ignored in interpret mode).
+
+    ``allow_input_fusion`` (one flag per operand) lets XLA fuse an operand's
+    producer, such as a layer's slice of stacked weights, into the kernel's
+    reads, instead of materializing it before the call."""
     if dimension_semantics is None:
         return None
     from jax.experimental.pallas import tpu as pltpu
-    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                allow_input_fusion=allow_input_fusion)
 
 
 def small_enough_off_tpu(*args, limit: int = 1 << 22) -> bool:

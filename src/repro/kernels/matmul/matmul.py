@@ -50,6 +50,9 @@ def mmm_pallas(a: jax.Array, b: jax.Array, *, bm: int = 256, bn: int = 256,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compiler_params(("parallel", "parallel", "arbitrary")),
+        # the operands' producers (a layer's slice of the stacked weights
+        # in a layer scan, the tile padding) fuse into the kernel's reads
+        compiler_params=compiler_params(("parallel", "parallel", "arbitrary"),
+                                        allow_input_fusion=(True, True)),
         interpret=interpret,
     )(a, b)

@@ -5,8 +5,8 @@ import functools
 
 import jax
 
-from ..common import (block_choices, clamp_block, interpret_default, pad_dim,
-                      pick_block)
+from ..common import (block_choices, clamp_block, divisor_block,
+                      interpret_default, pad_dim, pick_block)
 from .matmul import mmm_pallas
 
 
@@ -23,9 +23,11 @@ def _mmm_impl(a, b, bm, bn, bk, interpret):
 def _mmm_raw(a, b, interpret: bool, bm=None, bn=None, bk=None):
     m, k = a.shape
     _, n = b.shape
+    # weight tiles divide N and K where they can: a padded weight is a copy
+    # made before the call instead of a read inside it
     bm = pick_block(m, 256, 8) if bm is None else clamp_block(bm, m, 8)
-    bn = pick_block(n, 256, 128) if bn is None else clamp_block(bn, n, 128)
-    bk = pick_block(k, 512, 128) if bk is None else clamp_block(bk, k, 128)
+    bn = divisor_block(n, 256, 128) if bn is None else clamp_block(bn, n, 128)
+    bk = divisor_block(k, 512, 128) if bk is None else clamp_block(bk, k, 128)
     return _mmm_impl(a, b, bm, bn, bk, interpret)
 
 

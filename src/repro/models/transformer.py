@@ -254,22 +254,66 @@ def _run_stage(st: Stage, sp, x, *, cfg, positions, shared_params,
     body_fn = jax.checkpoint(body) if mode == "train" else body
     xs = (sp, caches) if caches is not None else sp
     carry0 = (x, jnp.zeros((), jnp.float32))
-    if st.repeats <= 2:
-        # short stages run as straight-line code (no while loop): the SPMD
-        # partitioner shards loop-free bodies strictly better, and the
-        # dry-run cost probes need every instruction visible exactly once
-        carry = carry0
-        ys_list = []
-        for r in range(st.repeats):
-            xs_r = jax.tree.map(lambda t: t[r], xs)
-            carry, ys_r = body_fn(carry, xs_r)
-            ys_list.append(ys_r)
-        x, aux = carry
-        ys = None if ys_list[0] is None else jax.tree.map(
-            lambda *ts: jnp.stack(ts), *ys_list)
-        return x, aux, ys
-    (x, aux), ys = jax.lax.scan(body_fn, carry0, xs)
+    (x, aux), ys = _scan_layers(body_fn, carry0, xs, st.repeats)
     return x, aux, ys
+
+
+def _scan_layers(body_fn, carry0, xs, repeats: int):
+    """``lax.scan`` of ``body_fn`` over a stage's ``repeats`` stacked layers.
+
+    Short stages run as straight-line code (no while loop): the SPMD
+    partitioner shards loop-free bodies strictly better, and the dry-run
+    cost probes need every instruction visible exactly once."""
+    if repeats > 2:
+        return jax.lax.scan(body_fn, carry0, xs)
+    carry = carry0
+    ys_list = []
+    for r in range(repeats):
+        xs_r = jax.tree.map(lambda t: t[r], xs)
+        carry, ys_r = body_fn(carry, xs_r)
+        ys_list.append(ys_r)
+    ys = None if ys_list[0] is None else jax.tree.map(
+        lambda *ts: jnp.stack(ts), *ys_list)
+    return carry, ys
+
+
+def _run_stage_paged(st: Stage, sp, layout, paged, x, *, cfg, positions,
+                     shared_params, tables, active, block_size: int):
+    """One stage of a paged decode step (DESIGN.md §14).
+
+    The sequence arenas are closed over and each layer reads its own
+    one-layer view through the block table inside the scan; lane leaves
+    (Mamba state) are scanned over as the dense path scans its caches.  The
+    scan emits, per sequence leaf, only the entry each lane wrote, and per
+    lane leaf the new state with inactive lanes' kept — never a view."""
+    from ..serve.kvcache import _is_spec, layer_view, written_entry
+
+    pos = positions[:, 0]
+    act = jnp.asarray(active, bool)
+    lanes = jax.tree.map(lambda ls, a: a if ls.kind == "lane" else None,
+                         layout, paged, is_leaf=_is_spec)
+
+    def body(x, xs):
+        x = shard(x, "batch", "seq_act", None)
+        lp, lc, r = xs
+        ys = []
+        for j, spec in enumerate(st.pattern):
+            cj = tuple(
+                old if ls.kind == "lane"
+                else layer_view(ls, arena, tables, r, block_size)
+                for ls, arena, old in zip(layout[j], paged[j], lc[j]))
+            x, _, nc = _apply_block(
+                spec, lp[j], x, cfg=cfg, positions=positions,
+                shared_params=shared_params, cache=cj, cache_pos=pos)
+            ys.append(tuple(
+                jnp.where(act.reshape((-1,) + (1,) * (new.ndim - 1)), new,
+                          old.astype(new.dtype)) if ls.kind == "lane"
+                else written_entry(ls, new, pos)
+                for ls, new, old in zip(layout[j], nc, cj)))
+        return x, tuple(ys)
+
+    return _scan_layers(body, x, (sp, lanes, jnp.arange(st.repeats)),
+                        st.repeats)
 
 
 def _embed_inputs(params, batch, cfg: ArchConfig):
@@ -430,6 +474,37 @@ class Model:
             new_caches = jax.tree.map(keep, new_caches, caches)
         logits = _masked_logits(params, x, cfg)
         return logits[:, 0], new_caches
+
+    def paged_decode_step(self, params, paged, layout, tables, token, pos,
+                          active, block_size: int
+                          ) -> Tuple[jax.Array, PyTree]:
+        """One decode step over block-paged caches (DESIGN.md §14).
+
+        ``paged`` is the tree ``serve.kvcache.init_paged`` builds and
+        ``layout`` its ``leaf_layout``; ``tables`` is the (B, max_blocks)
+        block table, ``pos``/``active`` are (B,).  Each layer attends over
+        the view :meth:`decode_step` would get from ``gather_views`` —
+        the same values in the same order, so the logits are bit-identical
+        — but reads it from the arenas inside the layer scan.  Returns the
+        logits and a tree of what to write back: per sequence leaf the
+        ``(R, B, ...)`` entries written this step (sequence axis dropped),
+        per lane leaf the new ``(R, B, ...)`` state
+        (``serve.kvcache.write_entries`` stores both)."""
+        cfg = self.cfg
+        x = embed_tokens(params["embed"], token).astype(
+            cfg.activation_dtype())
+        x = shard(x, "batch", None, None)
+        positions = jnp.asarray(pos, jnp.int32)[:, None]
+        entries = []
+        for i, st in enumerate(cfg.stages):
+            x, ys = _run_stage_paged(
+                st, params["stages"][i], layout[i], paged[i], x, cfg=cfg,
+                positions=positions, shared_params=params.get("shared"),
+                tables=tables, active=active, block_size=block_size)
+            entries.append(ys)
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = _masked_logits(params, x, cfg)
+        return logits[:, 0], entries
 
 
 def build_model(cfg: ArchConfig) -> Model:

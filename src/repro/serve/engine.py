@@ -45,7 +45,7 @@ from .kvcache import (BlockPool, LeafSpec, NoFreeBlocks, _is_spec,
                       copy_block, evict_slot, gather_views, init_paged,
                       insert_slot, leaf_layout, pad_caches,
                       prefix_block_keys, ring_lengths, scatter_slots,
-                      scatter_token)
+                      write_entries)
 
 log = logging.getLogger("repro.serve.engine")
 
@@ -256,11 +256,13 @@ class PagedEngine:
       up front (``can_admit``), so decode never exhausts the arena
       mid-flight: overload surfaces at admission, as policy.
 
-    Decode gathers each lane's blocks into a dense per-lane view, runs the
-    *unmodified* ``model.decode_step`` on it, and scatters the one written
-    entry per leaf back — masked garbage beyond each lane's position scores
-    exactly -1e30 either way, so paged decode is bit-identical to the dense
-    slot engine (the parity suite asserts it).  ``release_slot`` is
+    Decode leaves the arenas in place: ``model.paged_decode_step`` reads
+    each layer's per-lane view through the block table inside its layer
+    scan, runs the unmodified attention math on it, and hands back only the
+    entry each lane wrote, which ``write_entries`` scatters into the donated
+    arenas — masked garbage beyond each lane's position scores exactly
+    -1e30 either way, so paged decode is bit-identical to the dense slot
+    engine (the parity suite asserts it).  ``release_slot`` is
     host-only bookkeeping (refcounts, no device work), which is what lets
     failed lanes free their blocks even when the device pool is broken."""
 
@@ -356,10 +358,10 @@ class PagedEngine:
 
     def _decode_fn(self, params, paged, tok, tables, pos, active, key,
                    temperature):
-        views = gather_views(self.layout, paged, tables, self.block_size)
-        logits, views = self.model.decode_step(params, views, tok, pos,
-                                               active)
-        paged = scatter_token(self.layout, paged, views, tables, pos,
+        logits, entries = self.model.paged_decode_step(
+            params, paged, self.layout, tables, tok, pos, active,
+            self.block_size)
+        paged = write_entries(self.layout, paged, entries, tables, pos,
                               active, self.block_size)
         return paged, sample_tokens(logits, key, temperature)
 
@@ -528,7 +530,7 @@ class PagedEngine:
         Host prep per active lane: grow the tail block if this position
         crosses a block boundary, then COW-fence the write set — after
         which every block written this step is private, so the jitted
-        gather → decode → scatter touches no shared storage."""
+        decode and its write-back touch no shared storage."""
         with self.spans.span("serve.decode.prep"):
             for i, on in enumerate(active):
                 if on:

@@ -6,11 +6,13 @@ Two storage models over the same model-produced cache tree:
   dim over stacked layers) and a *slot* is a batch lane on axis 1:
   ``insert_slot`` / ``evict_slot`` / ``pad_caches``.
 * **Block-paged** — sequence-bearing leaves are re-laid-out as one arena of
-  fixed-size blocks per leaf, ``(R, num_blocks, ..., block_size, ...)``,
-  indexed through a per-slot block table: :class:`BlockPool` (host
-  refcounted allocator with prefix-hash reuse), ``leaf_layout`` /
-  ``init_paged`` (planning), ``gather_views`` (blocks → dense per-lane view
-  for the unmodified decode math), ``scatter_token`` / ``scatter_slots``
+  fixed-size blocks per leaf, ``(R, num_blocks, block_size, F)`` (each
+  position's ``F`` values one row), indexed through a per-slot block table:
+  :class:`BlockPool` (host refcounted allocator with prefix-hash reuse),
+  ``leaf_layout`` / ``init_paged`` (planning), ``gather_views`` (blocks →
+  dense per-lane views of every layer, for the chunk path) and
+  ``layer_view`` (one layer's, read inside the decode layer scan) for the
+  unmodified attention math, ``write_entries`` / ``scatter_slots``
   (written entries → arena), ``copy_block`` (COW fork).
 
 Block 0 of every arena is the *null block*: never allocated, kept all-zero
@@ -21,6 +23,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -121,10 +124,14 @@ class LeafSpec:
     ``"lane"`` for O(1) per-lane state (Mamba conv/SSM — kept dense and
     slot-indexed).  ``seq_axis``/``length`` describe the stacked
     ``(R, B, ...)`` dense leaf; position ``p`` lives at ring slot
-    ``p % length`` (identity for full-length leaves)."""
+    ``p % length`` (identity for full-length leaves).  ``feat`` is the dense
+    leaf's other per-position axes in order (``(kv, dh)`` for GQA,
+    ``(width,)`` for MLA): one position's entry, flattened, is a row of
+    ``F = prod(feat)`` values in its block."""
     kind: str
     seq_axis: int = 0
     length: int = 0
+    feat: Tuple[int, ...] = ()
 
 
 def _is_spec(x) -> bool:
@@ -143,11 +150,13 @@ def leaf_layout(cfg: ArchConfig, max_len: int) -> PyTree:
             if spec.kind == "mamba":
                 blocks.append((LeafSpec("lane"),) * 3)   # conv_x, conv_bc, ssm
             elif a.kv_lora:
-                blocks.append((LeafSpec("seq", 2, max_len),
-                               LeafSpec("seq", 2, max_len)))
+                blocks.append((LeafSpec("seq", 2, max_len, (a.kv_lora,)),
+                               LeafSpec("seq", 2, max_len,
+                                        (a.rope_head_dim,))))
             else:
                 lr = ring_len(cfg, a, max_len)
-                blocks.append((LeafSpec("seq", 3, lr), LeafSpec("seq", 3, lr)))
+                kv = LeafSpec("seq", 3, lr, (a.n_kv_heads, a.head_dim))
+                blocks.append((kv, kv))
         out.append(tuple(blocks))
     return out
 
@@ -162,19 +171,26 @@ def ring_lengths(layout: PyTree, max_len: int) -> List[int]:
 def init_paged(cfg: ArchConfig, slots: int, max_len: int, num_blocks: int,
                block_size: int) -> PyTree:
     """Zero-initialized paged cache tree: sequence leaves become
-    ``(R, num_blocks, ..., block_size, ...)`` arenas, lane leaves stay the
-    dense ``(R, slots, ...)`` slot-indexed state."""
+    ``(R, num_blocks, block_size, F)`` arenas, one row of ``F`` values per
+    position, lane leaves stay the dense ``(R, slots, ...)`` slot-indexed
+    state.
+
+    Folding the features into one minor axis keeps it a multiple of the
+    TPU's 128 lanes wherever ``F`` is (640 for h2o-danube-1.8b's 8 KV heads
+    of 80; MLA's 64-wide rope key is not).  With ``head_dim`` 80 as the
+    minor axis the device's default layout makes the block axis minor
+    instead, and every program that gathers or scatters blocks relayouts
+    the whole arena first."""
     from ..models.transformer import cache_specs
 
     specs = cache_specs(cfg, slots, max_len)
     layout = leaf_layout(cfg, max_len)
 
     def build(ls: LeafSpec, sp):
-        shape = list(sp.shape)
         if ls.kind == "seq":
-            shape[1] = num_blocks
-            shape[ls.seq_axis] = block_size
-        return jnp.zeros(tuple(shape), sp.dtype)
+            return jnp.zeros((sp.shape[0], num_blocks, block_size,
+                              math.prod(ls.feat)), sp.dtype)
+        return jnp.zeros(sp.shape, sp.dtype)
 
     return jax.tree.map(build, layout, specs, is_leaf=_is_spec)
 
@@ -182,70 +198,98 @@ def init_paged(cfg: ArchConfig, slots: int, max_len: int, num_blocks: int,
 # ---------------------------------------------------------------------------
 # Paged device ops (traced inside the engine's jitted programs)
 # ---------------------------------------------------------------------------
+def _positions(ls: LeafSpec, rows: jax.Array, block_size: int) -> jax.Array:
+    """Gathered blocks ``(..., m, block_size, F)`` → the first ``length``
+    positions, ``(..., length, *feat)``."""
+    lead = rows.shape[:-3]
+    seq = rows.reshape(lead + (rows.shape[-3] * block_size,) + ls.feat)
+    return jax.lax.slice_in_dim(seq, 0, ls.length, axis=len(lead))
+
+
+def _write_rows(arena: jax.Array, bid: jax.Array, off: jax.Array,
+                val: jax.Array) -> jax.Array:
+    """Store ``val`` ``(R, n, F)``: entry ``i`` goes to block ``bid[i]`` at
+    offset ``off[i]`` on every layer — one scatter indexed by layer, block
+    and offset, whose window is the arena's minor axis alone, so the arena
+    keeps its layout."""
+    layers = jnp.arange(arena.shape[0])[:, None]
+    return arena.at[layers, bid, off].set(val.astype(arena.dtype))
+
+
 def gather_views(layout: PyTree, paged: PyTree, tables: jax.Array,
                  block_size: int) -> PyTree:
     """Blocks → dense per-lane views, ``(R, B, ..., length, ...)`` per leaf.
 
     ``tables`` is the (B, max_blocks) int32 block table.  Each leaf gathers
-    the first ``ceil(length / block_size)`` table entries and flattens them
-    back into a contiguous sequence axis, sliced to exactly the dense row
-    length — so the unmodified decode/chunk attention math runs on the view
+    the first ``ceil(length / block_size)`` table entries and unflattens
+    their rows back into the dense leaf's axes, sliced to exactly the dense
+    row length — so the unmodified chunk attention math runs on the view
     and never sees the block structure.  Unwritten positions read whatever
     their block holds (zeros from the null block, stale KV from a reused
-    one); the per-lane position masks exclude them exactly, so decode on a
-    gathered view is bit-identical to decode on the dense slot cache."""
+    one); the per-lane position masks exclude them exactly, so attention
+    over a gathered view is bit-identical to attention over the dense slot
+    cache."""
 
     def g(ls: LeafSpec, arena):
         if ls.kind == "lane":
             return arena
         m = -(-ls.length // block_size)
-        rows = jnp.take(arena, tables[:, :m], axis=1)   # (R,B,m,...,bs,...)
-        rows = jnp.moveaxis(rows, 2, ls.seq_axis)       # block dim beside bs
-        shp = rows.shape
-        view = rows.reshape(shp[:ls.seq_axis] + (m * block_size,)
-                            + shp[ls.seq_axis + 2:])
-        return jax.lax.slice_in_dim(view, 0, ls.length, axis=ls.seq_axis)
+        rows = jnp.take(arena, tables[:, :m], axis=1)   # (R,B,m,bs,F)
+        return jnp.moveaxis(_positions(ls, rows, block_size), 2,
+                            ls.seq_axis)
 
     return jax.tree.map(g, layout, paged, is_leaf=_is_spec)
 
 
-def scatter_token(layout: PyTree, paged: PyTree, views: PyTree,
+def layer_view(ls: LeafSpec, arena: jax.Array, tables: jax.Array, layer,
+               block_size: int) -> jax.Array:
+    """One layer's dense per-lane view of a sequence arena,
+    ``(B, ..., length, ...)``: what :func:`gather_views` gives at index
+    ``layer`` of its stacked view, gathered straight from the arena (one
+    gather over the layer and block axes, so nothing arena-sized is
+    sliced or copied).  ``layer`` may be traced (the decode layer scan)."""
+    m = -(-ls.length // block_size)
+    rows = arena[layer, tables[:, :m]]                  # (B,m,bs,F)
+    return jnp.moveaxis(_positions(ls, rows, block_size), 1,
+                        ls.seq_axis - 1)
+
+
+def written_entry(ls: LeafSpec, view: jax.Array, pos: jax.Array
+                  ) -> jax.Array:
+    """The entry each lane wrote at ring slot ``pos % length`` of its
+    one-layer view: ``(B, *feat)``, the sequence axis dropped."""
+    axis = ls.seq_axis - 1
+    idx = jnp.mod(pos, ls.length).reshape((-1,) + (1,) * (view.ndim - 1))
+    return jnp.squeeze(jnp.take_along_axis(view, idx, axis=axis), axis)
+
+
+def write_entries(layout: PyTree, paged: PyTree, entries: PyTree,
                   tables: jax.Array, pos: jax.Array, active: jax.Array,
                   block_size: int) -> PyTree:
-    """Write each lane's single decode-step cache entry back into the arenas.
+    """Write one decode step's entries back into the arenas, in place.
 
-    ``pos``/``active`` are (B,) — every sequence leaf wrote exactly ring
-    slot ``pos % length`` in its view; that entry is extracted and scattered
-    to ``(tables[lane, slot // bs], slot % bs)``.  Inactive lanes are
-    redirected to the null block with a zero value, so block 0 stays
-    all-zero and no shared block is ever touched (COW forking made every
-    written block private before this runs).  Lane leaves (Mamba state) are
-    replaced wholesale — the model already masked inactive lanes."""
-    b = tables.shape[0]
+    ``entries`` mirrors the cache tree: a sequence leaf holds the ``(R, B,
+    *feat)`` entries each lane wrote at ring slot ``pos % length``, a lane
+    leaf the new ``(R, B, ...)`` state, which replaces the old wholesale
+    (the model already kept inactive lanes').  Each sequence leaf takes one
+    scatter into the donated arena.  Inactive lanes are redirected to the
+    null block with a zero value, so block 0 stays all-zero and no shared
+    block is ever touched (COW forking made every written block private
+    before this runs)."""
 
-    def s(ls: LeafSpec, arena, view):
+    def w(ls: LeafSpec, arena, entry):
         if ls.kind == "lane":
-            return view
+            return entry
         slot = jnp.mod(pos, ls.length)
         bid = jnp.take_along_axis(tables, (slot // block_size)[:, None],
                                   axis=1)[:, 0]
-        off = jnp.mod(slot, block_size)
-        bid = jnp.where(active, bid, 0)
-        off = jnp.where(active, off, 0)
-        idx = slot.reshape((1, b) + (1,) * (view.ndim - 2))
-        val = jnp.take_along_axis(view, idx, axis=ls.seq_axis)
-        msk = active.reshape((1, b) + (1,) * (view.ndim - 2))
-        val = jnp.where(msk, val, jnp.zeros((), val.dtype))
-        val = jnp.squeeze(val, axis=ls.seq_axis)         # (R, B, ...)
-        if ls.seq_axis != 2:
-            # advanced indices separated by a slice: batch dims move first
-            val = jnp.moveaxis(val, 1, 0)
-        loc: list = [slice(None)] * arena.ndim
-        loc[1] = bid
-        loc[ls.seq_axis] = off
-        return arena.at[tuple(loc)].set(val.astype(arena.dtype))
+        val = entry.reshape(entry.shape[:2] + (-1,))    # (R, B, F)
+        val = jnp.where(active[None, :, None], val,
+                        jnp.zeros((), val.dtype))
+        return _write_rows(arena, jnp.where(active, bid, 0),
+                           jnp.where(active, slot % block_size, 0), val)
 
-    return jax.tree.map(s, layout, paged, views, is_leaf=_is_spec)
+    return jax.tree.map(w, layout, paged, entries, is_leaf=_is_spec)
 
 
 def scatter_slots(ls: LeafSpec, arena: jax.Array, view: jax.Array,
@@ -257,16 +301,11 @@ def scatter_slots(ls: LeafSpec, arena: jax.Array, view: jax.Array,
     ``0..min(S0, length)`` of the padded prefill cache, the chunk path
     writes ``(p0 + arange(C)) % length`` (injective while C ≤ ring length,
     which the engine's chunk clamp guarantees)."""
-    bid = jnp.take(table_row, slots // block_size)
-    off = jnp.mod(slots, block_size)
-    val = jnp.take(view, slots, axis=ls.seq_axis)
-    val = jnp.squeeze(val, axis=1)                       # drop the lane dim
-    if ls.seq_axis != 2:
-        val = jnp.moveaxis(val, ls.seq_axis - 1, 0)
-    loc: list = [slice(None)] * arena.ndim
-    loc[1] = bid
-    loc[ls.seq_axis] = off
-    return arena.at[tuple(loc)].set(val.astype(arena.dtype))
+    val = jnp.take(view[:, 0], slots, axis=ls.seq_axis - 1)
+    val = jnp.moveaxis(val, ls.seq_axis - 1, 1)          # (R, n, *feat)
+    return _write_rows(arena, jnp.take(table_row, slots // block_size),
+                       jnp.mod(slots, block_size),
+                       val.reshape(val.shape[:2] + (-1,)))
 
 
 def copy_block(layout: PyTree, paged: PyTree, src: jax.Array,

@@ -106,3 +106,57 @@ def test_dispatch_scope_keeps_the_kernel_name(one_chip, no_persistent_cache):
         assert calls and all("tpu_custom_call" in ln for ln in calls), name
         assert all(f'op_name="jit(step)/halo.{alias}/' in ln
                    for ln in calls), name
+
+
+def test_paged_decode_holds_no_arena_or_stack_copy(one_chip,
+                                                    no_persistent_cache,
+                                                    monkeypatch):
+    """``PagedEngine._decode`` at h2o-danube-1.8b's widths (4 of its 24
+    layers, so the layers run as a scan), 8 slots of 2,560 positions: its
+    temporaries stay under one layer's key and value views, and no
+    arena-shaped buffer is copied.  Gathering every layer's view up front
+    takes a stack of them, and an arena the device lays out with another
+    axis minor than its gathers and scatters want is relaid whole on every
+    step."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serve import engine as engine_mod
+
+    cfg = get_config("h2o-danube-1.8b")
+    cfg = dataclasses.replace(cfg, stages=tuple(
+        dataclasses.replace(s, repeats=4) for s in cfg.stages))
+    init = engine_mod.init_paged
+    monkeypatch.setattr(engine_mod, "init_paged",
+                        lambda *a: jax.eval_shape(lambda: init(*a)))
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    slots, max_len = 8, 2560
+    eng = engine_mod.PagedEngine(model, params, slots, max_len)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    vec = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=one_chip)
+    compiled = eng._decode.lower(
+        on_chip(params), on_chip(eng.paged),
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip),
+        on_chip(jnp.asarray(eng.tables)), vec(jnp.int32), vec(bool),
+        on_chip(jax.random.PRNGKey(0)), 0.0).compile()
+
+    a = cfg.stages[0].pattern[0].attn
+    # one layer's K and V views, (slots, kv, max_len, head_dim) in bf16
+    # with head_dim padded to the chip's 128 lanes
+    layer_views = 2 * slots * a.n_kv_heads * max_len * 128 * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_views, (temp, layer_views)
+    arenas = {",".join(map(str, leaf.shape))
+              for leaf in jax.tree.leaves(eng.paged)}
+    copies = []
+    for ln in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) copy(?:-start)?\(", ln)
+        if m and arenas & set(re.findall(r"\[([\d,]+)\]", m.group(1))):
+            copies.append(ln)
+    assert not copies, copies[:2]
