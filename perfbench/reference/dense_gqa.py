@@ -5,7 +5,8 @@ causal attention with an optional sliding window, SwiGLU, untied head.
 Written from the published description in ``jax.numpy`` and float32 at
 ``highest`` matmul precision, one sequence at a time, with no cache, no
 batching and no kernel.  It imports nothing of the program: the weights
-come from ``weights.py`` and the sizes from the configuration file.
+come from the architecture's adapter (``arch/dense_gqa.py``, drawn by
+``weights.py``) and the sizes from the configuration file.
 
 ``fp8=True`` is the control: every matrix product takes its two
 operands rounded to float8 e4m3 (activations scaled per row, weights per

@@ -9,10 +9,12 @@ one JSON line last on standard output: ``correct``, ``attempted``,
 ``--trace 1`` its per-layer metrics), ``device`` and, traced, the trace's
 ``breakdown``; ``checks``, last, holds each compared number beside its
 limit.  Everything a cell needs is found by name: the configuration file
-the cell names, ``traffic/<traffic>.json``, ``cells/<cell>.json`` (the
-limits of its comparison) and ``metrics/<metric>.py`` (one reader per
-metric).  Without a TPU, or with fewer chips than the cell asks for, it
-exits non-zero and prints no result.
+the cell names, the adapter ``arch/<architecture>.py`` and the plain
+reference ``reference/<reference>.py`` that file names,
+``traffic/<traffic>.json``, ``cells/<cell>.json`` (the limits of its
+comparison) and ``metrics/<metric>.py`` (one reader per metric).
+Without a TPU, or with fewer chips than the cell asks for, it exits
+non-zero and prints no result.
 """
 from __future__ import annotations
 

@@ -43,39 +43,44 @@ class Sent:
 
 class CallLog:
     """Host spans and work counts of the engine's device calls (traced runs
-    only)."""
+    only): per call, the operations it needs and, per kernel event name,
+    the least time that kernel's calls need on this chip."""
 
     def __init__(self, cfg: dict, slots: int, peaks: dict):
         self.cfg, self.slots, self.peaks = cfg, slots, peaks
-        self.calls: List[tuple] = []          # (program, t, flops, roofline s)
+        self.calls: List[tuple] = []    # (program, t, flops, {kernel: s})
+        #: roofline seconds by (kernel, its calls): most calls repeat a shape
+        self._roof: Dict[tuple, float] = {}
+
+    def _record(self, prog: str, work: dict) -> None:
+        t = time.perf_counter()
+        pf, bw = self.peaks["bf16_flops_per_s"], self.peaks["hbm_bytes_per_s"]
+        roof = {}
+        for k, w in work["kernels"].items():
+            key = (k, tuple(w))
+            if key not in self._roof:
+                self._roof[key] = workcount.kernel_seconds(key[1], pf, bw)
+            roof[k] = self._roof[key]
+        self.calls.append((prog, t, work["flops"], roof))
 
     def wrap(self, engine) -> None:
         import jax
 
-        cfg, log = self.cfg, self.calls
-        pf, bw = self.peaks["bf16_flops_per_s"], self.peaks["hbm_bytes_per_s"]
-        decode_roof = workcount.roofline_seconds(
-            workcount.mmm_calls_decode(cfg, self.slots), pf, bw)
-        chunk_roof = {}
+        cfg, slots, record = self.cfg, self.slots, self._record
+        arch = program.adapter(cfg)
         decode_step = engine.decode_step
         continue_admission = engine.continue_admission
 
         def traced_decode(tok, pos, active, key, temperature=0.0):
             lanes = [int(p) for p, a in zip(pos, active) if a]
-            log.append(("jit__decode_fn", time.perf_counter(),
-                        workcount.decode_flops(cfg, lanes), decode_roof))
+            record("jit__decode_fn", arch.decode_work(cfg, lanes, slots))
             with jax.profiler.TraceAnnotation("perfbench.decode"):
                 return decode_step(tok, pos, active, key, temperature)
 
         def traced_chunk(slot):
             meta = engine._meta[slot]
             c = min(engine.chunk_tokens, len(meta.prompt) - meta.pos)
-            if c not in chunk_roof:
-                chunk_roof[c] = workcount.roofline_seconds(
-                    workcount.mmm_calls_chunk(cfg, c), pf, bw)
-            log.append(("jit__chunk_fn", time.perf_counter(),
-                        workcount.prefill_chunk_flops(cfg, c, meta.pos),
-                        chunk_roof[c]))
+            record("jit__chunk_fn", arch.chunk_work(cfg, c, meta.pos))
             with jax.profiler.TraceAnnotation("perfbench.chunk"):
                 return continue_admission(slot)
 
@@ -87,18 +92,19 @@ class CallLog:
         del engine.decode_step, engine.continue_admission
 
     def per_program(self, t0: float, t1: float) -> Dict[str, dict]:
-        """Mean operations and roofline seconds per call of each program,
-        over the calls issued in [t0, t1)."""
+        """Mean operations, and mean roofline seconds of each kernel, per
+        call of each program, over the calls issued in [t0, t1)."""
         out: Dict[str, dict] = {}
         for prog, t, fl, roof in self.calls:
             if t0 <= t < t1:
-                o = out.setdefault(prog, {"n": 0, "flops": 0.0, "roof_s": 0.0})
+                o = out.setdefault(prog, {"n": 0, "flops": 0.0, "roof_s": {}})
                 o["n"] += 1
                 o["flops"] += fl
-                o["roof_s"] += roof
+                for k, s in roof.items():
+                    o["roof_s"][k] = o["roof_s"].get(k, 0.0) + s
         for o in out.values():
             o["flops_mean"] = o["flops"] / o["n"]
-            o["roof_s_mean"] = o["roof_s"] / o["n"]
+            o["roof_s_mean"] = {k: s / o["n"] for k, s in o["roof_s"].items()}
         return out
 
 
@@ -139,14 +145,13 @@ class Server:
         from repro.models.transformer import build_model
         from repro.serve.engine import PagedEngine, StepScheduler
 
-        import weights
-
+        arch = program.adapter(cfg)
         self.cfg, self.traffic, self.log = cfg, traffic, log
         self.slots = traffic["slots"]
         sel = program.Selections()
         t0 = time.perf_counter()
-        model = build_model(program.arch_config(cfg))
-        params = weights.make_params(cfg, seed)
+        model = build_model(arch.arch_config(cfg))
+        params = arch.make_params(cfg, seed)
         jax.block_until_ready(params)
         t1 = time.perf_counter()
         max_len = loadgen.max_len(traffic)
@@ -459,12 +464,10 @@ def check(cfg: dict, seed: int, out: dict, traffic: dict) -> dict:
     """The comparison that decides ``correct``, run once the program's
     state is freed: finished requests drawn from the seed, replayed
     through the float32 reference."""
-    import weights
-
     picked = sample(out, seed, traffic["check"])
     if not picked:
         return {"logit_gap": math.inf, "requests": 0, "served_tokens": 0}
-    params = weights.make_params(cfg, seed)
+    params = program.adapter(cfg).make_params(cfg, seed)
     gaps = logit_gaps(cfg, params, picked, traffic)
     del params
     gc.collect()
@@ -476,13 +479,11 @@ def fp8_in_place(cfg: dict, seed: int, out: dict, traffic: dict) -> None:
     """The control: the requests that ``check`` will compare get, in place
     of the tokens the program served, the tokens the fp8 reference puts
     first at the same positions of the same prompts and served tokens."""
-    import weights
-
     picked = sample(out, seed, traffic["check"])
     if not picked:
         return
     ref = _reference(cfg)
-    params = weights.make_params(cfg, seed)
+    params = program.adapter(cfg).make_params(cfg, seed)
     for s in picked:
         toks, at = _positions(s)
         lo = ref.logits(cfg, params, toks, at, _at_len(traffic), fp8=True,

@@ -1,7 +1,7 @@
 """The plain reference's own properties at a small size on the CPU."""
 import numpy as np
 
-import weights
+from arch.dense_gqa import make_params
 from reference import dense_gqa
 
 TINY = {"hidden_size": 64, "intermediate_size": 128,
@@ -12,7 +12,7 @@ TINY = {"hidden_size": 64, "intermediate_size": 128,
 
 
 def test_padded_positions_give_the_same_logits():
-    params = weights.make_params(TINY, 5)
+    params = make_params(TINY, 5)
     toks = list(np.random.default_rng(0).integers(0, 256, 20))
     at = [3, 9, 19]
     a = np.asarray(dense_gqa.logits(TINY, params, toks, at, 3, bucket=16))
@@ -24,7 +24,7 @@ def test_padded_positions_give_the_same_logits():
 def test_window_hides_keys_beyond_it():
     # one layer: a query at p sees keys p-7..p, so token 0 moves the
     # logits up to position 7 and none from position 8 on
-    params = weights.make_params(TINY, 6)
+    params = make_params(TINY, 6)
     toks = list(np.random.default_rng(1).integers(1, 256, 12))
     other = [0] + toks[1:]
     at = list(range(12))

@@ -5,6 +5,7 @@ from pathlib import Path
 import pytest
 
 import workcount as w
+from arch import dense_gqa as a
 
 CONFIGS = Path(__file__).resolve().parent.parent / "configs"
 
@@ -26,28 +27,28 @@ MISTRAL_LARGE_3 = {"hidden_size": 12288, "intermediate_size": 28672,
 
 def test_danube_per_layer_and_total():
     c = cfg("h2o-danube-1.8b")
-    assert w.params_per_layer(c) == 69_473_280           # 69.47 M
-    assert w.params_total(c) == 1_831_201_280             # 1.831 B
-    assert 2 * w.params_total(c) == 3_662_402_560         # bytes in bf16
-    assert w.kv_bytes_per_token(c) == 61_440
+    assert a.params_per_layer(c) == 69_473_280           # 69.47 M
+    assert a.params_total(c) == 1_831_201_280             # 1.831 B
+    assert 2 * a.params_total(c) == 3_662_402_560         # bytes in bf16
+    assert a.kv_bytes_per_token(c) == 61_440
 
 
 def test_danube_training_stage():
     # one pipeline stage of 6 layers with the embedding and the head
     c = danube_layers(6)
-    assert w.params_total(c) == 580_682_240               # 580.7 M
+    assert a.params_total(c) == 580_682_240               # 580.7 M
     # 2.99 GFLOP a trained token in matmuls
-    assert 6 * w.matmul_flops_per_token(c) / 2 == pytest.approx(2.992e9,
+    assert 6 * a.matmul_flops_per_token(c) / 2 == pytest.approx(2.992e9,
                                                                 rel=1e-3)
 
 
 def test_mistral_large_stage():
     c = MISTRAL_LARGE_3
     # q, o 12288x12288; k, v 12288x1024; gate, up, down 12288x28672
-    assert w.params_per_layer(c) == 1_384_144_896
+    assert a.params_per_layer(c) == 1_384_144_896
     # three layers and both pipeline ends: 9.9 GB in bf16
-    assert w.params_total(c) == 3 * 1_384_144_896 + 2 * 32768 * 12288 + 12288
-    assert w.kv_bytes_per_token(c) == 12_288
+    assert a.params_total(c) == 3 * 1_384_144_896 + 2 * 32768 * 12288 + 12288
+    assert a.kv_bytes_per_token(c) == 12_288
 
 
 def test_attention_counts_only_the_mask():
@@ -56,7 +57,7 @@ def test_attention_counts_only_the_mask():
     assert w.context(c, 4095) == 4096
     assert w.context(c, 9000) == 4096                     # window
     assert w.context(MISTRAL_LARGE_3, 9000) == 9001
-    assert w.attn_flops(c, 99) == 24 * 4 * 32 * 80 * 100
+    assert a.attn_flops(c, 99) == 24 * 4 * 32 * 80 * 100
 
 
 def test_mmm_and_roofline():
@@ -72,12 +73,12 @@ def test_mmm_and_roofline():
 
 def test_program_calls_cover_every_layer():
     c = cfg("h2o-danube-1.8b")
-    dec = w.mmm_calls_decode(c, 16)
+    dec = a.mmm_calls_decode(c, 16)
     assert len(dec) == 24 * 7 + 1
     assert sum(2 * m * k * n for m, k, n in dec) == \
-        16 * w.matmul_flops_per_token(c)
-    assert w.decode_flops(c, [10]) == w.matmul_flops_per_token(c) + \
-        w.attn_flops(c, 10)
-    ch = w.prefill_chunk_flops(c, 32, 256)
-    assert ch == 32 * w.matmul_flops_per_token(c, with_head=False) + sum(
-        w.attn_flops(c, p) for p in range(256, 288)) + 2 * 2560 * 32000
+        16 * a.matmul_flops_per_token(c)
+    assert a.decode_flops(c, [10]) == a.matmul_flops_per_token(c) + \
+        a.attn_flops(c, 10)
+    ch = a.prefill_chunk_flops(c, 32, 256)
+    assert ch == 32 * a.matmul_flops_per_token(c, with_head=False) + sum(
+        a.attn_flops(c, p) for p in range(256, 288)) + 2 * 2560 * 32000
